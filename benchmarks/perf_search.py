@@ -20,7 +20,7 @@ FAST = os.environ.get("REPRO_BENCH_FAST", "1") != "0"
 
 def run(report=None):
     import jax
-    from jax.sharding import Mesh
+    from jax.sharding import AxisType, Mesh
 
     from repro.core.distributed import pad_database, sharded_nn_search
     from repro.data.synthetic import random_walks
@@ -33,9 +33,11 @@ def run(report=None):
 
     devs = np.array(jax.devices())
     if devs.size >= 8:
-        mesh = Mesh(devs.reshape(2, 4), ("data", "model"))
+        mesh = Mesh(
+            devs.reshape(2, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2
+        )
     else:
-        mesh = Mesh(devs.reshape(devs.size), ("data",))
+        mesh = Mesh(devs.reshape(devs.size), ("data",), axis_types=(AxisType.Auto,))
 
     rows = []
 

@@ -33,7 +33,10 @@ queries = random_walks(rng, 10, 256)
 
 db = Database.build(data, SearchConfig(w=25, block=16))
 devs = np.array(jax.devices())
-mesh = Mesh(devs.reshape(2, 4), ("data", "model"))
+mesh = Mesh(
+    devs.reshape(2, 4), ("data", "model"),
+    axis_types=(jax.sharding.AxisType.Auto,) * 2,
+)
 db.use_mesh(mesh, sync_every=4)
 print(f"mesh {dict(zip(mesh.axis_names, mesh.devices.shape))}, db {db.n_rows} series")
 print(db.plan(queries).explain())

@@ -31,6 +31,7 @@ from __future__ import annotations
 
 import dataclasses
 import os
+import threading
 
 import jax.numpy as jnp
 import numpy as np
@@ -59,6 +60,11 @@ from repro.mv.layout import flatten_channels
 from repro.stream.state import STD_EPS
 
 BUNDLE_FORMAT_VERSION = 1
+
+#: rows per device call when ``build`` computes the database envelopes:
+#: the device holds one chunk and its two envelopes at a time, never the
+#: whole database (16384 rows x 1000 samples is 64 MiB of float32)
+ENVELOPE_CHUNK_ROWS = 16384
 
 
 def _znorm_rows(
@@ -90,6 +96,22 @@ def _require_x64_for(config: SearchConfig) -> None:
             "building/loading; with x64 disabled device ops would "
             "silently downcast"
         )
+
+
+def _envelopes_in_chunks(
+    rows: np.ndarray, w: int, d: int
+) -> tuple[np.ndarray, np.ndarray]:
+    """Envelopes of every row, ``ENVELOPE_CHUNK_ROWS`` rows per device
+    call, gathered on the host.  Rows are enveloped independently, so
+    the chunking changes no value."""
+    n_db = rows.shape[0]
+    upper = np.empty_like(rows)
+    lower = np.empty_like(rows)
+    for lo in range(0, n_db, ENVELOPE_CHUNK_ROWS):
+        hi = min(lo + ENVELOPE_CHUNK_ROWS, n_db)
+        u, l = envelope_batch_mv(jnp.asarray(rows[lo:hi]), w, d)
+        upper[lo:hi], lower[lo:hi] = np.asarray(u), np.asarray(l)
+    return upper, lower
 
 
 class Database:
@@ -155,7 +177,11 @@ class Database:
         # a pure function of (calibration, k), so one sweep serves every
         # plan()/search() of the session (tests pin the count)
         self._cascade_cache: dict[int, CascadePlan] = {}
-        self._db_j = jnp.asarray(self.data)  # device-resident, uploaded once
+        # single-device copy of the rows, uploaded on first use by a
+        # single-device driver (see _db_j); a session with a mesh holds
+        # only the sharded copy
+        self._db_dev = None
+        self._db_lock = threading.Lock()
         self.mesh = None
         self._axis_names: tuple[str, ...] | None = None
         self._sync_every = 4
@@ -247,8 +273,7 @@ class Database:
         raw64 = np.asarray(flat, np.float64)
         row_sums = raw64.sum(axis=1)
         row_sumsq = (raw64 * raw64).sum(axis=1)
-        u, l = envelope_batch_mv(jnp.asarray(rows), w, d)
-        upper, lower = np.asarray(u), np.asarray(l)
+        upper, lower = _envelopes_in_chunks(rows, w, d)
 
         tri: TriangleIndex | None = None
         if index is True:
@@ -523,12 +548,24 @@ class Database:
             f"mesh={'attached' if self.mesh is not None else 'none'})"
         )
 
+    @property
+    def _db_j(self):
+        """The rows on the default device, uploaded once, on first use
+        by a single-device driver.  ``use_mesh`` releases this copy."""
+        with self._db_lock:
+            if self._db_dev is None:
+                self._db_dev = jnp.asarray(self.data)
+            return self._db_dev
+
     # ---------------------------------------------------------- sharding
 
     def use_mesh(self, mesh, axis_names=None, sync_every: int = 4) -> "Database":
         """Attach a device mesh: the planner then routes queries through
         the sharded driver.  The database is padded and placed onto the
-        mesh here, once — per-call ``device_put`` becomes a no-op."""
+        mesh here, once — per-call ``device_put`` becomes a no-op.  Each
+        device receives only its own shard, and a single-device copy of
+        the rows is released (a later explicit single-device ``driver=``
+        uploads it again)."""
         import jax
         from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -545,6 +582,8 @@ class Database:
         self._db_sharded = jax.device_put(
             dbp, NamedSharding(mesh, P(self._axis_names))
         )
+        with self._db_lock:
+            self._db_dev = None
         return self
 
     # ----------------------------------------------------------- queries

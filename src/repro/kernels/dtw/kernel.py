@@ -43,18 +43,18 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.common import BIG, cummin_doubling, cumsum_doubling
 
 
-def _dtw_lane(q_ref, yrow_full, bound, out_ref, *, n: int, w: int, p):
-    """The band DP for one candidate lane; ``yrow_full`` is the lane's
-    padded row as a (1, n + 2w) value already resident in VMEM.  Shared
-    by both schedules — the bit-identity argument in code form."""
+def _dtw_lane(q_ref, y_ref, bound, out_ref, *, n: int, w: int, p):
+    """The band DP for one candidate lane; ``y_ref`` is the lane's
+    padded (1, n + 2w) row, already resident in VMEM.  Shared by both
+    schedules — the bit-identity argument in code form."""
     width = 2 * w + 1
     ks = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)  # band offset k
 
-    prev0 = jnp.full((1, width), BIG, jnp.float32).at[0, w].set(0.0)
+    prev0 = jnp.where(ks == w, 0.0, BIG).astype(jnp.float32)  # origin
 
     def row(state):
         i, prev = state
-        yrow = jax.lax.dynamic_slice(yrow_full, (0, i), (1, width))
+        yrow = y_ref[:, pl.ds(i, width)]
         qi = q_ref[0, i]
         diff = jnp.abs(qi - yrow)
         cost = diff if p == 1 else diff * diff
@@ -79,12 +79,16 @@ def _dtw_lane(q_ref, yrow_full, bound, out_ref, *, n: int, w: int, p):
 
     i, last = jax.lax.while_loop(cond, row, (jnp.int32(0), prev0))
     # finished: exact powered DTW; abandoned: a valid lower bound >= bound
-    out_ref[0, 0] = jnp.where(i == n, last[0, w], jnp.min(last))
+    # (cell k = w picked by a masked max: exact, and a (1, 1) value —
+    # the TPU stores vectors to VMEM, never scalars)
+    at_w = jnp.max(jnp.where(ks == w, last, -BIG), axis=1, keepdims=True)
+    low = jnp.min(last, axis=1, keepdims=True)
+    out_ref[...] = jnp.where(i == n, at_w, low)
 
 
 def _dtw_kernel(q_ref, ypad_ref, bound_ref, out_ref, *, n: int, w: int, p):
     """depth=1: the padded row arrives via the BlockSpec pipeline."""
-    _dtw_lane(q_ref, ypad_ref[...], bound_ref[0, 0], out_ref, n=n, w=w, p=p)
+    _dtw_lane(q_ref, ypad_ref, bound_ref[0, 0], out_ref, n=n, w=w, p=p)
 
 
 def _dtw_db_kernel(
@@ -97,7 +101,7 @@ def _dtw_db_kernel(
 
     def dma(slot, lane):
         return pltpu.make_async_copy(
-            ypad_hbm.at[pl.ds(lane, 1), :], y_vmem.at[slot], sem.at[slot]
+            ypad_hbm.at[lane], y_vmem.at[slot], sem.at[slot]
         )
 
     @pl.when(i == 0)
@@ -110,7 +114,9 @@ def _dtw_db_kernel(
         dma((i + 1) % 2, i + 1).start()
 
     dma(i % 2, i).wait()
-    _dtw_lane(q_ref, y_vmem[i % 2], bound_ref[0, 0], out_ref, n=n, w=w, p=p)
+    _dtw_lane(
+        q_ref, y_vmem.at[i % 2], bound_ref[0, 0], out_ref, n=n, w=w, p=p
+    )
 
 
 @functools.partial(
@@ -131,10 +137,15 @@ def dtw_banded_pallas(
     selects single-buffered BlockSpec staging (1) or the double-buffered
     row prefetch (2) — outputs are bit-identical either way."""
     b = cands_pad.shape[0]
+    # per-lane rows ride a unit axis ((B, 1, n + 2w) and (B, 1, 1)) so
+    # every block's last two dims are the array's own, as the TPU
+    # lowering requires
+    cands_pad = cands_pad[:, None, :]
+    bounds = bounds[:, :, None]
     q_spec = pl.BlockSpec((1, n), lambda i: (0, 0))
-    bound_spec = pl.BlockSpec((1, 1), lambda i: (i, 0))
-    out_spec = pl.BlockSpec((1, 1), lambda i: (i, 0))
-    out_shape = jax.ShapeDtypeStruct((b, 1), jnp.float32)
+    bound_spec = pl.BlockSpec((None, 1, 1), lambda i: (i, 0, 0))
+    out_spec = pl.BlockSpec((None, 1, 1), lambda i: (i, 0, 0))
+    out_shape = jax.ShapeDtypeStruct((b, 1, 1), jnp.float32)
     if depth == 1:
         kern = functools.partial(_dtw_kernel, n=n, w=w, p=p)
         out = pl.pallas_call(
@@ -142,19 +153,19 @@ def dtw_banded_pallas(
             grid=(b,),
             in_specs=[
                 q_spec,
-                pl.BlockSpec((1, n + 2 * w), lambda i: (i, 0)),
+                pl.BlockSpec((None, 1, n + 2 * w), lambda i: (i, 0, 0)),
                 bound_spec,
             ],
             out_specs=out_spec,
             out_shape=out_shape,
             interpret=interpret,
         )(q, cands_pad, bounds)
-        return out[:, 0]
+        return out[:, 0, 0]
     kern = functools.partial(_dtw_db_kernel, n=n, w=w, p=p)
     out = pl.pallas_call(
         kern,
         grid=(b,),
-        in_specs=[q_spec, pl.BlockSpec(memory_space=pltpu.ANY), bound_spec],
+        in_specs=[q_spec, pl.BlockSpec(memory_space=pl.ANY), bound_spec],
         out_specs=out_spec,
         out_shape=out_shape,
         scratch_shapes=[
@@ -163,4 +174,4 @@ def dtw_banded_pallas(
         ],
         interpret=interpret,
     )(q, cands_pad, bounds)
-    return out[:, 0]
+    return out[:, 0, 0]

@@ -64,7 +64,8 @@ def _fused_tile_compute(c, u, l, q, bound, *, w: int, n: int, p):
 
     Pure function of the tile values — every schedule variant funnels
     through here, which is the bit-identity argument in code form.
-    Returns (lb1, lb) as (tile_b,) vectors.
+    ``bound`` is the lane's (1, 1) powered pruning bound.  Returns
+    (lb1, lb) as (tile_b, 1) columns.
     """
     win = 2 * w + 1
     total = round_up(n + 2 * w, win)
@@ -76,7 +77,7 @@ def _fused_tile_compute(c, u, l, q, bound, *, w: int, n: int, p):
     under = jnp.maximum(l - c, 0.0)
     d1 = over + under  # one side is always 0
     cost1 = d1 if p == 1 else d1 * d1
-    lb1 = jnp.sum(cost1, axis=1)  # (tile_b,)
+    lb1 = jnp.sum(cost1, axis=1, keepdims=True)  # (tile_b, 1)
 
     alive = lb1 < bound  # per-lane predication of pass 2
 
@@ -105,7 +106,7 @@ def _fused_tile_compute(c, u, l, q, bound, *, w: int, n: int, p):
         under2 = jnp.maximum(hl - q, 0.0)
         d2 = over2 + under2
         cost2 = d2 if p == 1 else d2 * d2
-        return jnp.sum(cost2, axis=1)  # (tile_b,)
+        return jnp.sum(cost2, axis=1, keepdims=True)  # (tile_b, 1)
 
     # tile-granular skip: a fully-pruned tile pays pass 1 only
     lb2 = jax.lax.cond(
@@ -119,11 +120,11 @@ def _lb_fused_kernel(
 ):
     """depth=1: the candidate tile arrives via the BlockSpec pipeline."""
     lb1, lb = _fused_tile_compute(
-        c_ref[...], u_ref[...], l_ref[...], q_ref[...], bound_ref[0, 0],
+        c_ref[...], u_ref[...], l_ref[...], q_ref[...], bound_ref[...],
         w=w, n=n, p=p,
     )
-    lb1_ref[...] = lb1[None, :]  # (1, tile_b)
-    lb_ref[...] = lb[None, :]
+    lb1_ref[...] = lb1  # (tile_b, 1)
+    lb_ref[...] = lb
 
 
 def _lb_fused_db_qb_kernel(
@@ -160,11 +161,11 @@ def _lb_fused_db_qb_kernel(
 
     dma(g % 2, bi).wait()
     lb1, lb = _fused_tile_compute(
-        c_vmem[g % 2], u_ref[...], l_ref[...], q_ref[...], bound_ref[0, 0],
+        c_vmem[g % 2], u_ref[...], l_ref[...], q_ref[...], bound_ref[...],
         w=w, n=n, p=p,
     )
-    lb1_ref[...] = lb1[None, :]
-    lb_ref[...] = lb[None, :]
+    lb1_ref[...] = lb1
+    lb_ref[...] = lb
 
 
 def _lb_fused_db_bq_kernel(
@@ -205,11 +206,11 @@ def _lb_fused_db_bq_kernel(
         dma(bi % 2, bi).wait()
 
     lb1, lb = _fused_tile_compute(
-        c_vmem[bi % 2], u_ref[...], l_ref[...], q_ref[...], bound_ref[0, 0],
+        c_vmem[bi % 2], u_ref[...], l_ref[...], q_ref[...], bound_ref[...],
         w=w, n=n, p=p,
     )
-    lb1_ref[...] = lb1[None, :]
-    lb_ref[...] = lb[None, :]
+    lb1_ref[...] = lb1
+    lb_ref[...] = lb
 
 
 @functools.partial(
@@ -243,26 +244,35 @@ def lb_fused_qbatch_pallas(
     if b % tile_b:
         raise ValueError(f"batch {b} not a multiple of tile_b {tile_b}")
     nbt = b // tile_b
+    # per-lane rows ride a unit axis ((Q, 1, n) and (Q, 1, 1) in,
+    # (Q, B, 1) out) so every block's last two dims are the array's own
+    # or (8, 128)-aligned, as the TPU lowering requires
     out_shape = [
-        jax.ShapeDtypeStruct((nq, b), cands.dtype),
-        jax.ShapeDtypeStruct((nq, b), cands.dtype),
+        jax.ShapeDtypeStruct((nq, b, 1), cands.dtype),
+        jax.ShapeDtypeStruct((nq, b, 1), cands.dtype),
     ]
     lane_spec = (
-        (lambda qi, bi: (qi, 0)) if grid == "qb" else (lambda bi, qi: (qi, 0))
+        (lambda qi, bi: (qi, 0, 0))
+        if grid == "qb"
+        else (lambda bi, qi: (qi, 0, 0))
     )
     out_map = (
-        (lambda qi, bi: (qi, bi)) if grid == "qb" else (lambda bi, qi: (qi, bi))
+        (lambda qi, bi: (qi, bi, 0))
+        if grid == "qb"
+        else (lambda bi, qi: (qi, bi, 0))
     )
     lane_specs = [
-        pl.BlockSpec((1, n), lane_spec),
-        pl.BlockSpec((1, n), lane_spec),
-        pl.BlockSpec((1, n), lane_spec),
-        pl.BlockSpec((1, 1), lane_spec),
+        pl.BlockSpec((None, 1, n), lane_spec),
+        pl.BlockSpec((None, 1, n), lane_spec),
+        pl.BlockSpec((None, 1, n), lane_spec),
+        pl.BlockSpec((None, 1, 1), lane_spec),
     ]
     out_specs = [
-        pl.BlockSpec((1, tile_b), out_map),
-        pl.BlockSpec((1, tile_b), out_map),
+        pl.BlockSpec((None, tile_b, 1), out_map),
+        pl.BlockSpec((None, tile_b, 1), out_map),
     ]
+    lanes = (upper[:, None, :], lower[:, None, :], qs[:, None, :],
+             bounds[:, :, None])
     pall_grid = (nq, nbt) if grid == "qb" else (nbt, nq)
 
     if depth == 1:
@@ -278,8 +288,8 @@ def lb_fused_qbatch_pallas(
             out_specs=out_specs,
             out_shape=out_shape,
             interpret=interpret,
-        )(cands, upper, lower, qs, bounds)
-        return lb1, lb
+        )(cands, *lanes)
+        return lb1[:, :, 0], lb[:, :, 0]
 
     # depth == 2: candidates stay unblocked (compiler-chosen memory,
     # HBM on TPU); the kernel stages tiles into a two-slot VMEM buffer
@@ -289,7 +299,7 @@ def lb_fused_qbatch_pallas(
     lb1, lb = pl.pallas_call(
         kern,
         grid=pall_grid,
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY), *lane_specs],
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY), *lane_specs],
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[
@@ -297,5 +307,5 @@ def lb_fused_qbatch_pallas(
             pltpu.SemaphoreType.DMA((2,)),
         ],
         interpret=interpret,
-    )(cands, upper, lower, qs, bounds)
-    return lb1, lb
+    )(cands, *lanes)
+    return lb1[:, :, 0], lb[:, :, 0]

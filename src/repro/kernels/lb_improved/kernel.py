@@ -108,7 +108,7 @@ def _lb2_qbatch_kernel(hmax_ref, hmin_ref, q_ref, lb_ref, *, w: int, n: int, p):
     under = jnp.maximum(lower - q, 0.0)
     d = over + under
     cost = d if p == 1 else d * d if p == 2 else d**p
-    lb_ref[...] = jnp.sum(cost, axis=1)[None, :]  # (1, tile_b)
+    lb_ref[...] = jnp.sum(cost, axis=1, keepdims=True)  # (tile_b, 1)
 
 
 @functools.partial(jax.jit, static_argnames=("w", "n", "p", "tile_b", "interpret"))
@@ -128,7 +128,9 @@ def lb_improved_pass2_qbatch_pallas(
     per (query, candidate) pair since H(c, q) depends on the query — plus
     queries (Q, n) -> lb2 (Q, B).  The query axis is a grid dimension, so
     each lane's projections and its (1, n) query row stream through VMEM
-    together and one launch serves the whole batch.
+    together and one launch serves the whole batch.  Query rows ride a
+    unit axis ((Q, 1, n) in, (Q, B, 1) out) so every block's last two
+    dims are the array's own or (8, 128)-aligned.
     """
     nq, b, total = hpad_max.shape
     win = 2 * w + 1
@@ -141,10 +143,10 @@ def lb_improved_pass2_qbatch_pallas(
         in_specs=[
             pl.BlockSpec((1, tile_b, total), lambda qi, bi: (qi, bi, 0)),
             pl.BlockSpec((1, tile_b, total), lambda qi, bi: (qi, bi, 0)),
-            pl.BlockSpec((1, n), lambda qi, bi: (qi, 0)),
+            pl.BlockSpec((None, 1, n), lambda qi, bi: (qi, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, tile_b), lambda qi, bi: (qi, bi)),
-        out_shape=jax.ShapeDtypeStruct((nq, b), hpad_max.dtype),
+        out_specs=pl.BlockSpec((None, tile_b, 1), lambda qi, bi: (qi, bi, 0)),
+        out_shape=jax.ShapeDtypeStruct((nq, b, 1), hpad_max.dtype),
         interpret=interpret,
-    )(hpad_max, hpad_min, qs)
-    return out
+    )(hpad_max, hpad_min, qs[:, None, :])
+    return out[:, :, 0]

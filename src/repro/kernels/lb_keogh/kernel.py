@@ -88,7 +88,7 @@ def _lb_keogh_qbatch_kernel(c_ref, u_ref, l_ref, lb_ref, h_ref, *, p):
         cost = d * d
     else:
         cost = d**p
-    lb_ref[...] = jnp.sum(cost, axis=1)[None, :]  # (1, tile_b)
+    lb_ref[...] = jnp.sum(cost, axis=1, keepdims=True)  # (tile_b, 1)
     h_ref[...] = jnp.clip(c, l, u)[None]  # (1, tile_b, n)
 
 
@@ -117,7 +117,7 @@ def _lb_keogh_stream_qbatch_kernel(
         cost = d * d
     else:
         cost = d**p
-    lb_ref[...] = jnp.sum(cost, axis=1)[None, :]  # (1, tile_b)
+    lb_ref[...] = jnp.sum(cost, axis=1, keepdims=True)  # (tile_b, 1)
     h_ref[...] = jnp.clip(c, l, u)[None]  # (1, tile_b, n)
 
 
@@ -159,20 +159,20 @@ def lb_keogh_stream_qbatch_pallas(
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, length), lambda qi, bi: (0, 0)),
-            pl.BlockSpec((1, n), lambda qi, bi: (qi, 0)),
-            pl.BlockSpec((1, n), lambda qi, bi: (qi, 0)),
+            pl.BlockSpec((None, 1, n), lambda qi, bi: (qi, 0, 0)),
+            pl.BlockSpec((None, 1, n), lambda qi, bi: (qi, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, tile_b), lambda qi, bi: (qi, bi)),
+            pl.BlockSpec((None, tile_b, 1), lambda qi, bi: (qi, bi, 0)),
             pl.BlockSpec((1, tile_b, n), lambda qi, bi: (qi, bi, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((nq, b), segment.dtype),
+            jax.ShapeDtypeStruct((nq, b, 1), segment.dtype),
             jax.ShapeDtypeStruct((nq, b, n), segment.dtype),
         ],
         interpret=interpret,
-    )(segment, upper, lower)
-    return lb, h
+    )(segment, upper[:, None, :], lower[:, None, :])
+    return lb[:, :, 0], h
 
 
 @functools.partial(jax.jit, static_argnames=("p", "tile_b", "interpret"))
@@ -190,7 +190,10 @@ def lb_keogh_qbatch_pallas(
     The query axis is a second grid dimension: each candidate tile is
     streamed into VMEM once per query lane while the (1, n) envelope row
     for that lane is broadcast across the candidate grid axis, so one
-    launch serves the whole query batch.  B % tile_b == 0.
+    launch serves the whole query batch.  Per-lane rows ride a unit
+    axis ((Q, 1, n) in, (Q, B, 1) out) so every block's last two dims
+    are the array's own or (8, 128)-aligned, as the TPU lowering
+    requires.  B % tile_b == 0.
     """
     b, n = cands.shape
     nq = upper.shape[0]
@@ -203,17 +206,17 @@ def lb_keogh_qbatch_pallas(
         grid=grid,
         in_specs=[
             pl.BlockSpec((tile_b, n), lambda qi, bi: (bi, 0)),
-            pl.BlockSpec((1, n), lambda qi, bi: (qi, 0)),
-            pl.BlockSpec((1, n), lambda qi, bi: (qi, 0)),
+            pl.BlockSpec((None, 1, n), lambda qi, bi: (qi, 0, 0)),
+            pl.BlockSpec((None, 1, n), lambda qi, bi: (qi, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, tile_b), lambda qi, bi: (qi, bi)),
+            pl.BlockSpec((None, tile_b, 1), lambda qi, bi: (qi, bi, 0)),
             pl.BlockSpec((1, tile_b, n), lambda qi, bi: (qi, bi, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((nq, b), cands.dtype),
+            jax.ShapeDtypeStruct((nq, b, 1), cands.dtype),
             jax.ShapeDtypeStruct((nq, b, n), cands.dtype),
         ],
         interpret=interpret,
-    )(cands, upper, lower)
-    return lb, h
+    )(cands, upper[:, None, :], lower[:, None, :])
+    return lb[:, :, 0], h

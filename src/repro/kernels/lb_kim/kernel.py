@@ -44,21 +44,30 @@ def _kim_cost(d, p):
     return d**p
 
 
+def _lane(x, j: int):
+    """Column ``j`` of a 2-D tile as a (rows, 1) value.  A masked max
+    picks the element exactly; a one-lane slice at an unaligned offset
+    has no TPU vector layout."""
+    lanes = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    return jnp.max(jnp.where(lanes == j, x, -BIG), axis=1, keepdims=True)
+
+
 def _lb_kim_qbatch_kernel(c_ref, q_ref, mask_ref, lb_ref, *, p):
     c = c_ref[...]  # (tile_b, n) — candidate tile, shared by all queries
     q = q_ref[...]  # (1, n) — query lane program_id(0)
-    mask = mask_ref[...]  # (1, tile_b) entry mask, 0.0 = dead lane
-    d_first = _kim_cost(jnp.abs(c[:, 0] - q[0, 0]), p)
-    d_last = _kim_cost(jnp.abs(c[:, -1] - q[0, -1]), p)
-    d_max = _kim_cost(jnp.abs(jnp.max(c, axis=1) - jnp.max(q)), p)
-    d_min = _kim_cost(jnp.abs(jnp.min(c, axis=1) - jnp.min(q)), p)
+    mask = mask_ref[...]  # (tile_b, 1) entry mask, 0.0 = dead lane
+    n = c.shape[1]
+    d_first = _kim_cost(jnp.abs(_lane(c, 0) - _lane(q, 0)), p)
+    d_last = _kim_cost(jnp.abs(_lane(c, n - 1) - _lane(q, n - 1)), p)
+    d_max = _kim_cost(jnp.abs(jnp.max(c, axis=1, keepdims=True) - jnp.max(q)), p)
+    d_min = _kim_cost(jnp.abs(jnp.min(c, axis=1, keepdims=True) - jnp.min(q)), p)
     if p == jnp.inf:
         lb = jnp.maximum(
             jnp.maximum(d_first, d_last), jnp.maximum(d_max, d_min)
         )
     else:
         lb = jnp.maximum(d_first + d_last, jnp.maximum(d_max, d_min))
-    lb_ref[...] = jnp.where(mask[0] > 0, lb, BIG)[None, :]  # (1, tile_b)
+    lb_ref[...] = jnp.where(mask > 0, lb, BIG)  # (tile_b, 1)
 
 
 @functools.partial(jax.jit, static_argnames=("p", "tile_b", "interpret"))
@@ -75,8 +84,11 @@ def lb_kim_qbatch_pallas(
     cands (B, n), queries (Q, n), mask (Q, B) float entry mask ->
     lb (Q, B): powered LB_Kim where ``mask > 0``, BIG elsewhere.
     Each candidate tile streams into VMEM once per query lane; the
-    (1, n) query row and its (1, tile_b) mask slice broadcast across
-    the candidate grid axis.  B % tile_b == 0.
+    (1, n) query row and its (tile_b, 1) mask column broadcast across
+    the candidate grid axis.  Per-lane rows ride a unit axis — (Q, 1, n)
+    in, (Q, B, 1) out — so every block's last two dims are either the
+    array's own or (8, 128)-aligned, as the TPU lowering requires.
+    B % tile_b == 0.
     """
     b, n = cands.shape
     nq = qs.shape[0]
@@ -89,11 +101,11 @@ def lb_kim_qbatch_pallas(
         grid=grid,
         in_specs=[
             pl.BlockSpec((tile_b, n), lambda qi, bi: (bi, 0)),
-            pl.BlockSpec((1, n), lambda qi, bi: (qi, 0)),
-            pl.BlockSpec((1, tile_b), lambda qi, bi: (qi, bi)),
+            pl.BlockSpec((None, 1, n), lambda qi, bi: (qi, 0, 0)),
+            pl.BlockSpec((None, tile_b, 1), lambda qi, bi: (qi, bi, 0)),
         ],
-        out_specs=pl.BlockSpec((1, tile_b), lambda qi, bi: (qi, bi)),
-        out_shape=jax.ShapeDtypeStruct((nq, b), cands.dtype),
+        out_specs=pl.BlockSpec((None, tile_b, 1), lambda qi, bi: (qi, bi, 0)),
+        out_shape=jax.ShapeDtypeStruct((nq, b, 1), cands.dtype),
         interpret=interpret,
-    )(cands, qs, mask)
-    return lb
+    )(cands, qs[:, None, :], mask[:, :, None])
+    return lb[:, :, 0]

@@ -45,6 +45,7 @@ from repro.configs.registry import (
     get_shape,
 )
 from repro.distributed.sharding import ShardingRules, fit_tree, make_rules, use_rules
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.hlo_analysis import analyze_hlo
 from repro.launch.mesh import make_production_mesh, mesh_axis_sizes
 from repro.launch.policy import apply_overrides, optimizer_for_cell, parallel_for_cell
@@ -308,6 +309,7 @@ def main():
         "--override", action="append", default=[], help="key=value ParallelConfig override"
     )
     args = ap.parse_args()
+    enable_compile_cache()
 
     overrides = {}
     for item in args.override:

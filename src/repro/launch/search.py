@@ -30,6 +30,7 @@ import numpy as np
 from repro.api import Database, SearchConfig
 from repro.core.microbatch import drain_queries, iter_query_batches
 from repro.data.synthetic import random_walks
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 
 __all__ = ["drain_queries", "iter_query_batches", "main"]
@@ -208,6 +209,7 @@ def main():
         "lengths route through the anytime subsequence tier",
     )
     args = ap.parse_args()
+    enable_compile_cache()
 
     rng = np.random.default_rng(args.seed)
     db = load_session(args)

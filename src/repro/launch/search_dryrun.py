@@ -26,6 +26,7 @@ import jax.numpy as jnp  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
 from repro.core.distributed import _sharded_search_fn  # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 from repro.launch.hlo_analysis import analyze_hlo  # noqa: E402
 from repro.launch.mesh import make_production_mesh  # noqa: E402
 
@@ -117,6 +118,7 @@ def main():
     ap.add_argument("--block", type=int, default=32)
     ap.add_argument("--sync-every", type=int, default=4)
     args = ap.parse_args()
+    enable_compile_cache()
     meshes = ("pod", "multipod") if args.mesh == "both" else (args.mesh,)
     for mk in meshes:
         run_search_cell(mk, block=args.block, sync_every=args.sync_every)

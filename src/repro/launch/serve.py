@@ -24,6 +24,7 @@ import numpy as np
 
 from repro.api import Database, SearchConfig
 from repro.data.synthetic import random_walks
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serve import QueryEngine
 
 
@@ -127,6 +128,7 @@ def main():
     ap.add_argument("--stream-threshold", type=float, default=3.0)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     rng = np.random.default_rng(args.seed)
     data = random_walks(rng, args.db_size, args.length)

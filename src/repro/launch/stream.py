@@ -26,6 +26,8 @@ import time
 
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
+
 
 def _parse_p(s: str):
     import jax.numpy as jnp
@@ -100,6 +102,7 @@ def main():
     ap.add_argument("--noise", type=float, default=0.05)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     from repro.api import Database, SearchConfig
     from repro.data.synthetic import planted_stream, template_bank

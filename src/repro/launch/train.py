@@ -19,6 +19,7 @@ import jax.numpy as jnp
 from repro.configs.base import ParallelConfig
 from repro.configs.registry import ARCH_IDS, get_config
 from repro.data.pipeline import SyntheticTokenPipeline
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model_zoo import build_model
 from repro.optim import OptimizerConfig, optimizer_init, warmup_cosine
 from repro.train import Trainer, TrainerConfig, make_train_step
@@ -36,6 +37,7 @@ def main():
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch, reduced=args.reduced)
     parallel = ParallelConfig(remat="none", compute_dtype="float32")

@@ -26,6 +26,7 @@ import argparse
 import json
 
 from repro.kernels.tuning import SESSION_FAMILIES, autotune_session
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def _parse_p(s: str):
@@ -58,6 +59,7 @@ def main(argv=None) -> int:
     ap.add_argument("--out", default="",
                     help="write the tuned TuneTable as JSON to this path")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     families = (
         tuple(f for f in args.families.split(",") if f)

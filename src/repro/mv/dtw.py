@@ -233,14 +233,18 @@ def dtw_qbatch_mv(
     powered: bool = False,
     d: int = 1,
 ) -> jax.Array:
-    """Doubly vmapped dependent DTW: (Q, d*n) x (B, d*n) -> (Q, B)."""
+    """Dependent DTW over every pair: (Q, d*n) x (B, d*n) -> (Q, B), the
+    pairs on one flat vmap axis like ``repro.core.dtw.dtw_qbatch``."""
     if d == 1:
         from repro.core.dtw import dtw_qbatch
 
         return dtw_qbatch(queries, candidates, w, p, powered)
-    return jax.vmap(lambda q: dtw_batch_mv(q, candidates, w, p, powered, d))(
-        queries
+    fn = dtw_banded_mv if p != jnp.inf else dtw_banded_diag_mv
+    nq, nb = queries.shape[0], candidates.shape[0]
+    vals = jax.vmap(lambda q, c: fn(q, c, w, p, powered, d))(
+        jnp.repeat(queries, nb, axis=0), jnp.tile(candidates, (nq, 1))
     )
+    return vals.reshape(nq, nb)
 
 
 def dtw_reference_mv(x, y, w: int, p: PNorm = 1) -> float:

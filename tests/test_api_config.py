@@ -114,6 +114,45 @@ def test_float64_requires_x64_at_build_and_load(tmp_path):
         Database.load(path)
 
 
+def test_float64_session_serves_every_driver_exactly_subprocess():
+    """Under x64 a float64 session answers through the host, scan and
+    sharded drivers alike, bit for bit, and matches the float64 oracle
+    (the scan and sharded sweeps once built int64 candidate ids under
+    x64 and refused to trace)."""
+    from helpers import run_in_subprocess
+
+    run_in_subprocess(
+        r"""
+import numpy as np
+from repro.api import Database, SearchConfig
+from repro.core.dtw import dtw_reference
+from repro.data.synthetic import random_walks
+from repro.launch.mesh import make_host_mesh
+
+rng = np.random.default_rng(0)
+data = random_walks(rng, 1100, 48)
+qs = random_walks(rng, 3, 48)
+db = Database.build(data, SearchConfig(precision="float64", k=3))
+host = db.search(qs, driver="host")
+scan = db.search(qs, driver="scan")
+db.use_mesh(make_host_mesh())
+sharded = db.search(qs)
+assert host.distances.dtype == np.float64
+for got in (scan, sharded):
+    assert np.array_equal(got.distances, host.distances)
+    assert np.array_equal(got.indices, host.indices)
+pq = db.prepare_queries(qs)
+for i in range(3):
+    for d, j in zip(host.distances[i], host.indices[i]):
+        ref = dtw_reference(pq[i], db.data[j], db.w)
+        assert abs(d - ref) <= 1e-9 * ref, (d, ref)
+print("F64 OK")
+""",
+        n_devices=4,
+        env_extra={"JAX_ENABLE_X64": "1"},
+    )
+
+
 def test_config_is_frozen():
     cfg = SearchConfig()
     with pytest.raises(dataclasses.FrozenInstanceError):

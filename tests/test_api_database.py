@@ -209,6 +209,30 @@ def test_device_array_uploaded_once(problem):
     assert db._db_j is a
 
 
+def test_device_copy_is_lazy(problem):
+    """build leaves the rows on the host; the first single-device search
+    uploads them, once."""
+    data, qs = problem
+    db = Database.build(data, SearchConfig(w=W))
+    assert db._db_dev is None
+    db.search(qs, driver="scan")
+    assert db._db_dev is not None
+
+
+def test_build_envelopes_chunked_bit_identical(problem, monkeypatch):
+    """Enveloping the database a chunk of rows at a time changes no
+    value: rows are enveloped independently."""
+    data, _ = problem
+    whole = Database.build(data, SearchConfig(w=W))
+    monkeypatch.setattr(api_db, "ENVELOPE_CHUNK_ROWS", 7)  # ragged tail
+    chunked = Database.build(data, SearchConfig(w=W))
+    np.testing.assert_array_equal(chunked.upper, whole.upper)
+    np.testing.assert_array_equal(chunked.lower, whole.lower)
+    u, l = api_db.envelope_batch_mv(np.asarray(data), whole.w, 1)
+    np.testing.assert_array_equal(whole.upper, np.asarray(u))
+    np.testing.assert_array_equal(whole.lower, np.asarray(l))
+
+
 def test_powered_norm_artifacts(problem):
     data, _ = problem
     db = Database.build(data, SearchConfig(w=W))
@@ -418,4 +442,38 @@ assert np.array_equal(got.distances, want.distances)
 assert np.array_equal(got.indices, want.indices)
 assert got.stats == want.stats
 """
+    )
+
+
+def test_use_mesh_places_one_shard_per_device_subprocess():
+    """With a mesh attached every device holds its own shard and no
+    device holds the whole database; an explicit single-device driver
+    uploads a copy again and agrees with the sharded sweep."""
+    run_in_subprocess(
+        r"""
+import numpy as np, jax
+from repro.api import Database, SearchConfig
+from repro.data.synthetic import random_walks
+from repro.launch.mesh import make_host_mesh
+
+rng = np.random.default_rng(1)
+data = random_walks(rng, 256, 64)
+qs = random_walks(rng, 3, 64)
+mesh = make_host_mesh()
+assert mesh.axis_types == (jax.sharding.AxisType.Auto,) * 2, mesh.axis_types
+db = Database.build(data, SearchConfig(w=6, k=2, block=8))
+db.search(qs, driver="scan")
+assert db._db_dev is not None
+db.use_mesh(mesh)
+assert db._db_dev is None
+shards = db._db_sharded.addressable_shards
+assert len({s.device for s in shards}) == 4
+assert all(s.data.shape == (64, 64) for s in shards), [s.data.shape for s in shards]
+got = db.search(qs)
+want = db.search(qs, driver="scan")
+assert np.array_equal(got.distances, want.distances)
+assert np.array_equal(got.indices, want.indices)
+print("PLACEMENT OK")
+""",
+        n_devices=4,
     )

@@ -34,6 +34,20 @@ from repro.kernels import (
 
 RNG = np.random.default_rng(5)
 
+
+def test_interpret_follows_the_backend_only():
+    """Kernels are interpreted exactly off the TPU: no switch can make a
+    chip run interpret them, or the CPU compile them."""
+    import inspect
+
+    import jax
+
+    from repro.kernels import common
+
+    assert common.interpret_default() is (jax.default_backend() != "tpu")
+    assert "environ" not in inspect.getsource(common)
+
+
 SHAPES = [(4, 32, 3), (8, 100, 10), (3, 65, 16), (16, 128, 12), (5, 47, 46)]
 
 
