@@ -36,6 +36,7 @@ import threading
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.api.config import SearchConfig
 from repro.api.planner import (
     Calibration,
@@ -716,6 +717,7 @@ class Database:
             "subsequence": qlen is not None and qlen != self.length,
         }
 
+    @obs.spanned("session.plan")
     def plan(
         self,
         queries=None,
@@ -768,6 +770,7 @@ class Database:
             channels=self.d,
         )
 
+    @obs.spanned("session.query")
     def search(
         self,
         queries,

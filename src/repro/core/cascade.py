@@ -43,6 +43,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.dtw import BIG, PNorm, finish_cost
 from repro.core import pipeline as pipe
 from repro.core.pipeline import Method, TriContext, run_block_stages
@@ -505,6 +506,7 @@ def _dtw_pairs_block_early(qrows, crows, w, bounds, p, d=1):
     )
 
 
+@obs.spanned("session.host")
 def nn_search_host(
     q: jax.Array,
     db: jax.Array,
@@ -533,6 +535,12 @@ def nn_search_host(
     *whole batch* are pooled into shared ``dtw_chunk``-sized DP
     dispatches, so nearly-empty per-query chunks disappear and DP lanes
     track total surviving work, not query count.
+
+    Spans (``repro.obs``): the call is ``session.host``; each block is a
+    ``session.host.block`` whose leaves tile it, ``session.host.lb`` per
+    LB stage, ``session.host.compact``, then ``session.host.dp`` and
+    ``session.host.merge`` per DP chunk; each blocking device-to-host
+    read is a ``session.host.wait`` inside the span that made it.
     """
     q = jnp.asarray(q)
     single = q.ndim == 1
@@ -560,63 +568,67 @@ def nn_search_host(
         top_v[qi], top_i[qi] = av[order], ai[order]
 
     for t in range(nb):
-        lo, hi = t * block, min((t + 1) * block, n_db)
-        blk = db_j[lo:hi]
-        if blk.shape[0] < block:  # pad the tail block once
-            pad = jnp.broadcast_to(blk[-1:], (block - blk.shape[0], n))
-            blk = jnp.concatenate([blk, pad], axis=0)
-        bound = top_v[:, -1]  # (Q,)
+        with obs.span("session.host.block"):
+            lo, hi = t * block, min((t + 1) * block, n_db)
+            blk = db_j[lo:hi]
+            if blk.shape[0] < block:  # pad the tail block once
+                pad = jnp.broadcast_to(blk[-1:], (block - blk.shape[0], n))
+                blk = jnp.concatenate([blk, pad], axis=0)
+            bound = top_v[:, -1]  # (Q,)
 
-        # LB stages as the method's pipeline declares them: the first
-        # sweeps the whole block, later ones only run while lanes survive
-        alive = np.ones((nq, hi - lo), bool)
-        for si, name in enumerate(lb_names):
-            if si > 0:
-                if not alive.any():
-                    break
-                if si == 1:  # once per block, however deep the cascade
-                    blocks_lb2 += 1
-            lb = np.asarray(
-                _dense_stage_qblock(name, qs, upper, lower, blk, w, p, d)
-            )[:, : hi - lo]
-            alive_next = alive & (lb < bound[:, None])
-            lb_pruned[si] += (alive & ~alive_next).sum(axis=1)
-            alive = alive_next
+            # LB stages as the method's pipeline declares them: the first
+            # sweeps the whole block, later ones only run while lanes survive
+            alive = np.ones((nq, hi - lo), bool)
+            for si, name in enumerate(lb_names):
+                if si > 0:
+                    if not alive.any():
+                        break
+                    if si == 1:  # once per block, however deep the cascade
+                        blocks_lb2 += 1
+                with obs.span("session.host.lb", stage=name):
+                    lb = _dense_stage_qblock(name, qs, upper, lower, blk, w, p, d)
+                    with obs.span("session.host.wait"):
+                        lb = np.asarray(lb)
+                    lb = lb[:, : hi - lo]
+                    alive_next = alive & (lb < bound[:, None])
+                    lb_pruned[si] += (alive & ~alive_next).sum(axis=1)
+                    alive = alive_next
 
-        # pooled survivor pairs: all queries' survivors of this block,
-        # query-major order so each chunk touches few top-k rows
-        pair_q, pair_c = np.nonzero(alive)
-        pair_c = pair_c + lo
-        c3 += alive.sum(axis=1)
-        for s0 in range(0, len(pair_q), dtw_chunk):
-            sel_q = pair_q[s0 : s0 + dtw_chunk]
-            sel_c = pair_c[s0 : s0 + dtw_chunk]
-            pad_n = dtw_chunk - len(sel_q)
-            sel_qp = np.concatenate([sel_q, np.repeat(sel_q[-1:], pad_n)])
-            sel_cp = np.concatenate([sel_c, np.repeat(sel_c[-1:], pad_n)])
-            blocks_dtw += 1
-            dp_lane_work += dtw_chunk
-            dp_lane_useful += len(sel_q)
-            if early_abandon:
-                dvals = np.array(
-                    _dtw_pairs_block_early(
-                        qs[sel_qp],
-                        db_j[sel_cp],
-                        w,
-                        jnp.asarray(top_v[sel_qp, -1]),
-                        p,
-                        d,
-                    )
-                )
-            else:
-                dvals = np.array(
-                    _dtw_pairs_block(qs[sel_qp], db_j[sel_cp], w, p, d)
-                )
-            if pad_n:
-                dvals[dtw_chunk - pad_n :] = BIG
-            for qi in np.unique(sel_qp):
-                sel = sel_qp == qi
-                merge(int(qi), dvals[sel], sel_cp[sel])
+            # pooled survivor pairs: all queries' survivors of this block,
+            # query-major order so each chunk touches few top-k rows
+            with obs.span("session.host.compact"):
+                pair_q, pair_c = np.nonzero(alive)
+                pair_c = pair_c + lo
+                c3 += alive.sum(axis=1)
+            for s0 in range(0, len(pair_q), dtw_chunk):
+                sel_q = pair_q[s0 : s0 + dtw_chunk]
+                sel_c = pair_c[s0 : s0 + dtw_chunk]
+                with obs.span("session.host.dp", pairs=dtw_chunk, useful=len(sel_q)):
+                    pad_n = dtw_chunk - len(sel_q)
+                    sel_qp = np.concatenate([sel_q, np.repeat(sel_q[-1:], pad_n)])
+                    sel_cp = np.concatenate([sel_c, np.repeat(sel_c[-1:], pad_n)])
+                    blocks_dtw += 1
+                    dp_lane_work += dtw_chunk
+                    dp_lane_useful += len(sel_q)
+                    if early_abandon:
+                        dvals = _dtw_pairs_block_early(
+                            qs[sel_qp],
+                            db_j[sel_cp],
+                            w,
+                            jnp.asarray(top_v[sel_qp, -1]),
+                            p,
+                            d,
+                        )
+                    else:
+                        dvals = _dtw_pairs_block(qs[sel_qp], db_j[sel_cp], w, p, d)
+                    with obs.span("session.host.wait"):
+                        dvals = np.array(dvals)
+                    if pad_n:
+                        dvals[dtw_chunk - pad_n :] = BIG
+                with obs.span("session.host.merge"):
+                    for qi in np.unique(sel_qp):
+                        sel = sel_qp == qi
+                        merge(int(qi), dvals[sel], sel_cp[sel])
 
     agg, per_query = _batch_stats(
         n_db,
@@ -629,7 +641,9 @@ def nn_search_host(
         dp_lane_work=dp_lane_work,
         dp_lane_useful=dp_lane_useful,
     )
-    distances = np.asarray(finish_cost(jnp.asarray(top_v), p))
+    distances = finish_cost(jnp.asarray(top_v), p)
+    with obs.span("session.host.wait"):
+        distances = np.asarray(distances)
     if single:
         return SearchResult(
             distances=distances[0], indices=top_i[0], stats=per_query[0]

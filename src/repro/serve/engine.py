@@ -45,6 +45,7 @@ concurrent with the batch worker.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import threading
 import time
@@ -53,6 +54,7 @@ from concurrent.futures import Future
 
 import numpy as np
 
+from repro import obs
 from repro.core.cascade import SearchResult, SearchStats
 from repro.core.microbatch import pad_rows
 from repro.serve.cache import AnswerCache, query_digest
@@ -560,30 +562,31 @@ class QueryEngine:
         with self._cv:
             self._n_batches += 1
             self._n_batch_lanes += n_valid
-        for i, lane in enumerate(lanes):
-            single = SearchResult(
-                distances=res.distances[i],
-                indices=res.indices[i],
-                stats=res.per_query[i] if res.per_query else res.stats,
-            )
-            self.cache.put(lane[0].digest, single)
-            for j, req in enumerate(lane):
-                wait_s = t_exec - req.t_submit
-                with self._cv:
-                    self._n_served += 1
-                    self._wait_s_sum += wait_s
-                req.future.set_result(
-                    Answer(
-                        distances=single.distances,
-                        indices=single.indices,
-                        stats=single.stats,
-                        tenant=req.tenant,
-                        cache_hit=False,
-                        coalesced=j > 0,
-                        wait_ms=1e3 * wait_s,
-                        batch_lanes=n_valid,
-                    )
+        with obs.span("engine.fanout", lanes=len(lanes)):
+            for i, lane in enumerate(lanes):
+                single = SearchResult(
+                    distances=res.distances[i],
+                    indices=res.indices[i],
+                    stats=res.per_query[i] if res.per_query else res.stats,
                 )
+                self.cache.put(lane[0].digest, single)
+                for j, req in enumerate(lane):
+                    wait_s = t_exec - req.t_submit
+                    with self._cv:
+                        self._n_served += 1
+                        self._wait_s_sum += wait_s
+                    req.future.set_result(
+                        Answer(
+                            distances=single.distances,
+                            indices=single.indices,
+                            stats=single.stats,
+                            tenant=req.tenant,
+                            cache_hit=False,
+                            coalesced=j > 0,
+                            wait_ms=1e3 * wait_s,
+                            batch_lanes=n_valid,
+                        )
+                    )
 
     def _execute_anytime(
         self, exec_key: tuple, lanes: list[list[_Request]], t_exec: float
@@ -641,25 +644,30 @@ class QueryEngine:
 
     def _run(self) -> None:
         while True:
-            with self._cv:
-                while self._pending == 0 and not self._closed:
-                    self._cv.wait(timeout=0.1)
-                if self._pending == 0 and self._closed:
-                    return
-                # max-wait/max-batch policy: hold the batch open until it
-                # fills or the oldest admitted request has waited max_wait
-                # (a closing engine drains immediately)
-                oldest = self._oldest_submit_locked()
-                if oldest is not None and not self._closed:
-                    t_limit = oldest + self.max_wait
-                    while self._pending < self.max_batch and not self._closed:
-                        left = t_limit - time.monotonic()
-                        if left <= 0:
-                            break
-                        self._cv.wait(timeout=left)
-                batch = self._form_batch_locked()
-            if batch is not None:
-                self._execute(*batch)
+            # one request id and one engine.batch span per batch, from its
+            # formation through the fan-out of its answers
+            with contextlib.ExitStack() as batch_scope:
+                with self._cv:
+                    while self._pending == 0 and not self._closed:
+                        self._cv.wait(timeout=0.1)
+                    if self._pending == 0 and self._closed:
+                        return
+                    # max-wait/max-batch policy: hold the batch open until
+                    # it fills or the oldest admitted request has waited
+                    # max_wait (a closing engine drains immediately)
+                    oldest = self._oldest_submit_locked()
+                    if oldest is not None and not self._closed:
+                        t_limit = oldest + self.max_wait
+                        while self._pending < self.max_batch and not self._closed:
+                            left = t_limit - time.monotonic()
+                            if left <= 0:
+                                break
+                            self._cv.wait(timeout=left)
+                    batch_scope.enter_context(obs.request())
+                    batch_scope.enter_context(obs.span("engine.batch"))
+                    batch = self._form_batch_locked()
+                if batch is not None:
+                    self._execute(*batch)
 
     # ------------------------------------------------------------ streaming
 

@@ -33,6 +33,7 @@ import math
 
 import numpy as np
 
+from repro import obs
 from repro.core.cascade import Method
 from repro.core.dtw import PNorm
 from repro.stream.state import STD_EPS, StreamState
@@ -142,6 +143,7 @@ class StreamMatcher:
     def stats(self) -> StreamStats:
         return self.scanner.stats
 
+    @obs.spanned("stream.push")
     def push(self, samples) -> None:
         """Ingest samples; sweeps every window block that completed.
 
@@ -156,7 +158,8 @@ class StreamMatcher:
         if self.d == 1:
             arr = np.asarray(samples).ravel()
             for lo in range(0, arr.size, bite):
-                self.state.push(arr[lo : lo + bite])
+                with obs.span("stream.ingest"):
+                    self.state.push(arr[lo : lo + bite])
                 self._sweep_full_blocks()
             return
         arr = np.asarray(samples)
@@ -212,6 +215,7 @@ class StreamMatcher:
     def _frontier(self) -> float:
         return math.inf if self._flushed else self._next_start
 
+    @obs.spanned("stream.resolve")
     def _resolve(self) -> None:
         acc, _rej, pend = suppress_stream(
             self._live_acc + self._pending, self._frontier, self.exclusion

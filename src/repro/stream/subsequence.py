@@ -50,6 +50,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.cascade import Method
 from repro.core.dtw import PNorm
 from repro.core.pipeline import lb_stage_names, run_block_stages
@@ -214,6 +215,12 @@ class StreamStats:
         return self.dp_lane_useful / self.dp_lane_work
 
 
+def _read(x) -> np.ndarray:
+    """A blocking device-to-host read, timed as ``stream.wait``."""
+    with obs.span("stream.wait"):
+        return np.asarray(x)
+
+
 class SubsequenceScanner:
     """Block engine: windows-as-lanes sweep of the template batch.
 
@@ -333,6 +340,14 @@ class SubsequenceScanner:
         """
         if n_valid <= 0:
             return []
+        with obs.span("stream.block", windows=int(n_valid)):
+            return self._block(state, start0, n_valid)
+
+    def _block(self, state, start0: int, n_valid: int) -> list[Match]:
+        """``process_block``'s body.  Its leaves tile the block:
+        ``stream.windows`` and ``stream.prefilter`` (in the lane builder),
+        ``stream.match`` (uploads and launch), a ``stream.wait`` per
+        device read, and ``stream.tally`` (stats and hits)."""
         n, hop, block = self.n, self.hop, self.block
         starts = start0 + hop * np.arange(block, dtype=np.int64)
         valid = np.arange(block) < n_valid
@@ -344,85 +359,89 @@ class SubsequenceScanner:
                 state, start0, avail, starts, valid
             )
 
-        res = _match_block_jit(
-            self._qs_j,
-            self._u_j,
-            self._l_j,
-            jnp.asarray(wins),
-            self._gate_j,
-            jnp.asarray(mask0),
-            self.w,
-            self.p,
-            self.method,
-            self.d,
-        )
-        d = np.asarray(res.d)
-        masks = [np.asarray(m) for m in res.masks]
+        with obs.span("stream.match"):
+            res = _match_block_jit(
+                self._qs_j,
+                self._u_j,
+                self._l_j,
+                jnp.asarray(wins),
+                self._gate_j,
+                jnp.asarray(mask0),
+                self.w,
+                self.p,
+                self.method,
+                self.d,
+            )
+        d = _read(res.d)
+        masks = [_read(m) for m in res.masks]
 
-        st = self.stats
-        st.n_windows += n_valid
-        for s in range(len(st.stage_names)):
-            st.stage_pruned[s] += (masks[s] & ~masks[s + 1]).sum(axis=1)
-        st.full_dtw += masks[-1].sum(axis=1)
-        st.blocks_total += 1
-        st.blocks_lb2 += int(res.need_lb2)
-        st.blocks_dtw += int(res.need_dtw)
-        st.dp_lane_work += int(res.dp_lane_work)
-        st.dp_lane_useful += int(res.dp_lane_useful)
+        with obs.span("stream.tally"):
+            st = self.stats
+            st.n_windows += n_valid
+            for s in range(len(st.stage_names)):
+                st.stage_pruned[s] += (masks[s] & ~masks[s + 1]).sum(axis=1)
+            st.full_dtw += masks[-1].sum(axis=1)
+            st.blocks_total += 1
+            st.blocks_lb2 += int(_read(res.need_lb2))
+            st.blocks_dtw += int(_read(res.need_dtw))
+            st.dp_lane_work += int(_read(res.dp_lane_work))
+            st.dp_lane_useful += int(_read(res.dp_lane_useful))
 
-        hit = d <= self.thr_pow[:, None]
-        st.matched += hit.sum(axis=1)
-        rooted = finish_np(d.astype(np.float64), self.p)
-        out = []
-        for qi, bi in zip(*np.nonzero(hit)):
-            out.append(Match(int(qi), int(starts[bi]), float(rooted[qi, bi])))
-        return out
+            hit = d <= self.thr_pow[:, None]
+            st.matched += hit.sum(axis=1)
+            rooted = finish_np(d.astype(np.float64), self.p)
+            out = []
+            for qi, bi in zip(*np.nonzero(hit)):
+                out.append(Match(int(qi), int(starts[bi]), float(rooted[qi, bi])))
+            return out
 
     def _window_lanes(self, state, start0, avail, starts, valid):
         """Univariate lane builder: (block, n) windows + S0 mask."""
         n, hop, block = self.n, self.hop, self.block
-        seg = state.view(start0, avail)
-        if avail < self.span:  # tail block: pad so strides stay static
-            seg = np.concatenate(
-                [seg, np.zeros(self.span - avail, seg.dtype)]
-            )
-        wins = np.lib.stride_tricks.sliding_window_view(seg, n)[::hop][
-            :block
-        ]
-
-        if self.znorm:
-            mean, std = state.window_mean_std(
-                np.where(valid, starts, starts[0]), n, self.eps
-            )
-            wins = znorm_windows(wins, mean, std)
-        else:
-            wins = np.ascontiguousarray(wins)
-            mean = std = None
-
-        mask0 = np.broadcast_to(valid[None, :], (self.nq, block)).copy()
-        if self.prefilter:
-            u_seg, l_seg = state.envelope_view(start0, avail)
-            if avail < self.span:
-                pad = self.span - avail
-                u_seg = np.concatenate([u_seg, np.zeros(pad, u_seg.dtype)])
-                l_seg = np.concatenate([l_seg, np.zeros(pad, l_seg.dtype)])
-            u_w = np.lib.stride_tricks.sliding_window_view(u_seg, n)[::hop][
+        with obs.span("stream.windows"):
+            seg = state.view(start0, avail)
+            if avail < self.span:  # tail block: pad so strides stay static
+                seg = np.concatenate(
+                    [seg, np.zeros(self.span - avail, seg.dtype)]
+                )
+            wins = np.lib.stride_tricks.sliding_window_view(seg, n)[::hop][
                 :block
             ]
-            l_w = np.lib.stride_tricks.sliding_window_view(l_seg, n)[::hop][
-                :block
-            ]
+
             if self.znorm:
-                u_w = ((u_w - mean[:, None]) / std[:, None]).astype(
-                    np.float32
+                mean, std = state.window_mean_std(
+                    np.where(valid, starts, starts[0]), n, self.eps
                 )
-                l_w = ((l_w - mean[:, None]) / std[:, None]).astype(
-                    np.float32
-                )
-            lb0 = envelope_prefilter(self.templates, u_w, l_w, self.p)
-            alive0 = mask0 & (lb0 < self.gate[:, None])
-            self.stats.env_pruned += (mask0 & ~alive0).sum(axis=1)
-            mask0 = alive0
+                wins = znorm_windows(wins, mean, std)
+            else:
+                wins = np.ascontiguousarray(wins)
+                mean = std = None
+
+            mask0 = np.broadcast_to(valid[None, :], (self.nq, block)).copy()
+        if self.prefilter:
+            with obs.span("stream.prefilter"):
+                u_seg, l_seg = state.envelope_view(start0, avail)
+                if avail < self.span:
+                    pad = self.span - avail
+                    u_seg = np.concatenate([u_seg, np.zeros(pad, u_seg.dtype)])
+                    l_seg = np.concatenate([l_seg, np.zeros(pad, l_seg.dtype)])
+                u_w = np.lib.stride_tricks.sliding_window_view(u_seg, n)[::hop][
+                    :block
+                ]
+                l_w = np.lib.stride_tricks.sliding_window_view(l_seg, n)[::hop][
+                    :block
+                ]
+                if self.znorm:
+                    u_w = ((u_w - mean[:, None]) / std[:, None]).astype(
+                        np.float32
+                    )
+                    l_w = ((l_w - mean[:, None]) / std[:, None]).astype(
+                        np.float32
+                    )
+                lb0 = envelope_prefilter(self.templates, u_w, l_w, self.p)
+                alive0 = mask0 & (lb0 < self.gate[:, None])
+                self.stats.env_pruned += (mask0 & ~alive0).sum(axis=1)
+                mask0 = alive0
         return wins, mask0
 
     def _window_lanes_mv(self, states, start0, avail, starts, valid):
