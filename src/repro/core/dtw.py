@@ -7,8 +7,17 @@ loop-carried dependency inside each row; here we restructure it for SIMD /
 TPU execution (see DESIGN.md section 3):
 
 * ``dtw_banded``   — row-wise DP where the within-row (min,+) recurrence is
-  solved in closed form with one ``cumsum`` + one ``cummin`` per row
-  (finite p).  n sequential steps, each a dense vector op of width 2w+1.
+  solved in closed form: the inclusive cost prefix sums S of every row are
+  one ``cumsum`` over the whole (n, 2w+1) band before the row loop, and
+  each row step is one ``cummin`` by doubling (ceil(log2(2w+1)) shifted
+  minimums).  n sequential steps, each a few dense vector ops of width
+  2w+1.  The TPU lowers ``cumsum``/``cummin`` to reduce-windows; with the
+  band on its 128 lanes those do O(band x 128) work per cell, and inside
+  the loop they took 6.0 of 6.92 us per 16-pair row at w = 100 on a v5e.
+  Vmapped over 128 pairs or more the pairs take the lanes, and the cumsum
+  stays in the loop, where it is then cheap (``_by_batch``).  One
+  ``_row_step`` serves this, the early-abandoning DP and their
+  multivariate twins (repro.mv.dtw).
 * ``dtw_banded_diag`` — anti-diagonal wavefront (2n-1 steps); handles all
   p including p = inf with purely elementwise ops.  This is the layout the
   Pallas kernel (repro.kernels.dtw) mirrors.
@@ -82,6 +91,141 @@ def _band_costs(x: jax.Array, y: jax.Array, w: int, p: PNorm) -> jax.Array:
     return jnp.where(valid, c, BIG), valid
 
 
+def cummin_doubling(x: jax.Array, axis: int = -1) -> jax.Array:
+    """Inclusive prefix min by Hillis-Steele doubling over a static shape:
+    ceil(log2(W)) shifted ``minimum``s, the shifted-in front padded with
+    BIG.  A min never rounds, so on values <= BIG this equals
+    ``lax.cummin`` bit for bit."""
+    n = x.shape[axis]
+    shift = 1
+    while shift < n:
+        pad = [(0, 0)] * x.ndim
+        pad[axis] = (shift, 0)
+        sl = [slice(None)] * x.ndim
+        sl[axis] = slice(0, n)
+        x = jnp.minimum(x, jnp.pad(x, pad, constant_values=BIG)[tuple(sl)])
+        shift *= 2
+    return x
+
+
+def _row_step(
+    prev: jax.Array,
+    cost_sum_row: jax.Array,
+    valid_row: jax.Array,
+    s_row: jax.Array | None = None,
+) -> jax.Array:
+    """One band row of the (min,+) DP from the row before it.
+
+    ``s_row`` is the inclusive cumsum of ``cost_sum_row``, taken for all
+    rows before the row loop where that pays (``_by_batch``); see
+    ``dtw_banded`` for the closed form.
+    """
+    if s_row is None:
+        s_row = jnp.cumsum(cost_sum_row)
+    up = jnp.concatenate([prev[1:], jnp.array([BIG], prev.dtype)])
+    b = jnp.minimum(up, prev)
+    t = jnp.where(valid_row, b + cost_sum_row - s_row, BIG)
+    # clip to keep BIG from overflowing after repeated additions
+    row = jnp.minimum(s_row + cummin_doubling(t), BIG)
+    return jnp.where(valid_row, row, BIG)
+
+
+def _row_inputs(costs: jax.Array, valid: jax.Array, w: int, hoist: bool):
+    """The row loop's inputs: the costs with out-of-band cells zeroed
+    (they enter S only), each row's cost prefix sums S if ``hoist``, and
+    the virtual row -1 holding the origin D[-1, -1] = 0 at k = w.
+
+    The origin feeds row 0 via "diag" only: row 0, cell k reads prev[k]
+    (diag -> D[-1, j-1], only j = 0 i.e. k = w is the origin) and
+    prev[k+1] (up -> D[-1, j], never valid).  "Up" from the origin would
+    be prev[k+1] = 0 at k = w-1, i.e. column j = -1, an invalid cell, so
+    it is harmless.
+    """
+    costs_sum = jnp.where(valid, costs, 0.0)
+    s = jnp.cumsum(costs_sum, axis=-1) if hoist else None
+    prev0 = jnp.full((2 * w + 1,), BIG, costs.dtype).at[w].set(0.0)
+    return costs_sum, s, prev0
+
+
+#: vmapped pairs from which the row loop keeps each row's cumsum inside
+#: the loop (``_by_batch``)
+WIDE_BATCH = 128
+
+
+def _by_batch(fn):
+    """``fn(*arrays, hoist=...)``, hoisting S out of the row loop unless
+    it runs vmapped over ``WIDE_BATCH`` pairs or more.
+
+    The TPU lowers ``cumsum`` to a reduce-window.  With few pairs XLA
+    lays the band on the 128 lanes, where that costs O(band x 128) per
+    cell, so one whole-band cumsum before the loop saves most of the DP
+    (v5e, n = 1000, w = 100: 16 pairs 4.44 ms with a cumsum per row, 1.78
+    hoisted).  From 128 pairs on it lays the pairs on the lanes: a cumsum
+    per row is then cheap, and the hoisted one costs more than it saves
+    and doubles the DP's memory (128 pairs: 1.54 ms, 7.43 hoisted).
+    Both give the per-row form's values: bit for bit on the CPU, and on
+    the v5e at every batch size measured but 64 pairs (1.3e-6 apart).
+    """
+
+    @jax.custom_batching.custom_vmap
+    def call(*args):
+        return fn(*args, hoist=True)
+
+    @call.def_vmap
+    def _batched(axis_size, in_batched, *args):
+        one = functools.partial(fn, hoist=axis_size < WIDE_BATCH)
+        axes = [0 if b else None for b in in_batched]
+        return jax.vmap(one, in_axes=axes)(*args), True
+
+    return call
+
+
+def _band_dp(costs, valid, *, w: int, hoist: bool):
+    costs_sum, s, prev0 = _row_inputs(costs, valid, w, hoist)
+
+    def step(prev, rows):
+        return _row_step(prev, *rows), None
+
+    last, _ = jax.lax.scan(step, prev0, (costs_sum, valid, s))
+    return last[w]  # cell (n-1, j=n-1) -> k = w
+
+
+def _band_dp_early(costs, valid, bound, *, w: int, hoist: bool):
+    costs_sum, s, prev0 = _row_inputs(costs, valid, w, hoist)
+    n = costs.shape[0]
+    ks = jnp.arange(2 * w + 1)
+
+    def cond(state):
+        i, prev = state
+        return (i < n) & (jnp.min(prev) < bound)
+
+    def step(state):
+        i, prev = state
+        # a row's validity from its index, not a per-lane gather of `valid`
+        j = i + ks - w
+        s_row = None if s is None else s[i]
+        return i + 1, _row_step(prev, costs_sum[i], (j >= 0) & (j < n), s_row)
+
+    i, last = jax.lax.while_loop(cond, step, (jnp.int32(0), prev0))
+    # abandoned: every cell >= bound, min(last) is a valid lower bound
+    return jnp.where(i == n, last[w], jnp.min(last))
+
+
+def band_dp(costs: jax.Array, valid: jax.Array, w: int) -> jax.Array:
+    """Powered DTW from the (n, 2w+1) band costs and their validity
+    mask: all n rows, a scan."""
+    return _by_batch(functools.partial(_band_dp, w=w))(costs, valid)
+
+
+def band_dp_early(
+    costs: jax.Array, valid: jax.Array, w: int, bound: jax.Array
+) -> jax.Array:
+    """``band_dp`` that stops once every band cell exceeds ``bound``
+    (powered): the exact value if it ran to the end, else the band min,
+    a lower bound >= ``bound``."""
+    return _by_batch(functools.partial(_band_dp_early, w=w))(costs, valid, bound)
+
+
 @functools.partial(jax.jit, static_argnames=("w", "p", "powered"))
 def dtw_banded(
     x: jax.Array, y: jax.Array, w: int, p: PNorm = 1, powered: bool = False
@@ -97,7 +241,14 @@ def dtw_banded(
 
         row[k] = S[k] + cummin(b + cost - S)[k],  S = inclusive cumsum(cost)
 
-    i.e. one cumsum + one cummin per row - no sequential inner loop.
+    so no sequential inner loop.  S depends on the costs alone, so one
+    ``cumsum`` over the whole band matrix gives every row's S before the
+    row loop starts (unless vmapped over ``WIDE_BATCH`` pairs or more,
+    see ``_by_batch``); in the loop the cummin is a doubling scan of
+    ceil(log2(2w+1)) shifted minimums (8 at w = 100).  Both keep the bits
+    of a per-row ``cumsum`` + ``lax.cummin``, and keep the TPU's
+    reduce-window lowering of those two (O(band x 128) work per cell
+    with the band on its lanes) out of the n serial steps.
     Out-of-band cells contribute 0 to S (so sums stay well-scaled) and BIG
     to the cummin argument (so no path can enter there); see dtw.py module
     docstring for why the resulting garbage in the invalid suffix is never
@@ -107,33 +258,7 @@ def dtw_banded(
         raise ValueError("use dtw_banded_diag for p = inf")
     n = _check_pair(x, y)
     w = int(min(w, n - 1))
-    width = 2 * w + 1
-
-    costs, valid = _band_costs(x, y, w, p)
-    costs_sum = jnp.where(valid, costs, 0.0)  # for the cumsum only
-
-    # prev row: D[0, j] in band coords of row i=0 reads; we start the scan
-    # at i=0 with a virtual row -1 holding the origin D[-1,-1]=0 at k=w.
-    prev0 = jnp.full((width,), BIG, x.dtype).at[w].set(0.0)
-    # But the origin must feed row 0 via "diag" only.  Row 0, cell k reads
-    # prev[k] (diag -> D[-1, j-1], only j=0 i.e. k=w is the origin) and
-    # prev[k+1] (up -> D[-1, j], never valid).  Setting prev0[w]=0 gives
-    # exactly diag-from-origin; "up" from the origin would be prev[k+1]=0
-    # at k=w-1 i.e. column j=-1, an invalid cell, so it is harmless.
-
-    def step(prev, inputs):
-        cost_row, cost_sum_row, valid_row = inputs
-        up = jnp.concatenate([prev[1:], jnp.array([BIG], prev.dtype)])
-        b = jnp.minimum(up, prev)
-        s = jnp.cumsum(cost_sum_row)
-        t = jnp.where(valid_row, b + cost_sum_row - s, BIG)
-        # clip to keep BIG from overflowing after repeated additions
-        row = jnp.minimum(s + jax.lax.cummin(t), BIG)
-        row = jnp.where(valid_row, row, BIG)
-        return row, None
-
-    last, _ = jax.lax.scan(step, prev0, (costs, costs_sum, valid))
-    out = last[w]  # cell (n-1, j=n-1) -> k = w
+    out = band_dp(*_band_costs(x, y, w, p), w)
     return out if powered else finish_cost(out, p)
 
 
@@ -243,32 +368,7 @@ def dtw_banded_early(
         raise ValueError("early abandon implemented for finite p")
     n = _check_pair(x, y)
     w = int(min(w, n - 1))
-    width = 2 * w + 1
-
-    costs, valid = _band_costs(x, y, w, p)
-    costs_sum = jnp.where(valid, costs, 0.0)
-    prev0 = jnp.full((width,), BIG, x.dtype).at[w].set(0.0)
-
-    def cond(state):
-        i, prev = state
-        return (i < n) & (jnp.min(prev) < bound)
-
-    def step(state):
-        i, prev = state
-        cost_row = costs[i]
-        cost_sum_row = costs_sum[i]
-        valid_row = valid[i]
-        up = jnp.concatenate([prev[1:], jnp.array([BIG], prev.dtype)])
-        b = jnp.minimum(up, prev)
-        s = jnp.cumsum(cost_sum_row)
-        t = jnp.where(valid_row, b + cost_sum_row - s, BIG)
-        row = jnp.minimum(s + jax.lax.cummin(t), BIG)
-        row = jnp.where(valid_row, row, BIG)
-        return i + 1, row
-
-    i, last = jax.lax.while_loop(cond, step, (jnp.int32(0), prev0))
-    # abandoned: every cell >= bound, min(last) is a valid lower bound
-    return jnp.where(i == n, last[w], jnp.min(last))
+    return band_dp_early(*_band_costs(x, y, w, p), w, bound)
 
 
 def dtw_reference(x, y, w: int, p: PNorm = 1) -> float:

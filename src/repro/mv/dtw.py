@@ -32,6 +32,8 @@ import numpy as np
 from repro.core.dtw import (
     BIG,
     PNorm,
+    band_dp,
+    band_dp_early,
     dtw_banded,
     dtw_banded_diag,
     dtw_banded_early,
@@ -84,8 +86,8 @@ def dtw_banded_mv(
 ) -> jax.Array:
     """Dependent DTW_p of flattened rows (d*n,) — row-scan form, finite p.
 
-    Same closed-form (min,+) row recurrence as ``dtw_banded``; only the
-    cell costs differ (channel-combined).  d = 1 dispatches to the
+    The same row loop as ``dtw_banded`` (``repro.core.dtw.band_dp``);
+    only the cell costs differ (channel-combined).  d = 1 dispatches to the
     univariate implementation verbatim.
     """
     if p == jnp.inf:
@@ -94,24 +96,7 @@ def dtw_banded_mv(
         return dtw_banded(x, y, w, p, powered)
     n = _check_pair_mv(x, y, d)
     w = int(min(w, n - 1))
-    width = 2 * w + 1
-
-    costs, valid = _band_costs_mv(x, y, w, p, d)
-    costs_sum = jnp.where(valid, costs, 0.0)
-    prev0 = jnp.full((width,), BIG, x.dtype).at[w].set(0.0)
-
-    def step(prev, inputs):
-        cost_row, cost_sum_row, valid_row = inputs
-        up = jnp.concatenate([prev[1:], jnp.array([BIG], prev.dtype)])
-        b = jnp.minimum(up, prev)
-        s = jnp.cumsum(cost_sum_row)
-        t = jnp.where(valid_row, b + cost_sum_row - s, BIG)
-        row = jnp.minimum(s + jax.lax.cummin(t), BIG)
-        row = jnp.where(valid_row, row, BIG)
-        return row, None
-
-    last, _ = jax.lax.scan(step, prev0, (costs, costs_sum, valid))
-    out = last[w]
+    out = band_dp(*_band_costs_mv(x, y, w, p, d), w)
     return out if powered else finish_cost(out, p)
 
 
@@ -182,30 +167,7 @@ def dtw_banded_early_mv(
         return dtw_banded_early(x, y, w, bound, p)
     n = _check_pair_mv(x, y, d)
     w = int(min(w, n - 1))
-    width = 2 * w + 1
-
-    costs, valid = _band_costs_mv(x, y, w, p, d)
-    costs_sum = jnp.where(valid, costs, 0.0)
-    prev0 = jnp.full((width,), BIG, x.dtype).at[w].set(0.0)
-
-    def cond(state):
-        i, prev = state
-        return (i < n) & (jnp.min(prev) < bound)
-
-    def step(state):
-        i, prev = state
-        cost_sum_row = costs_sum[i]
-        valid_row = valid[i]
-        up = jnp.concatenate([prev[1:], jnp.array([BIG], prev.dtype)])
-        b = jnp.minimum(up, prev)
-        s = jnp.cumsum(cost_sum_row)
-        t = jnp.where(valid_row, b + cost_sum_row - s, BIG)
-        row = jnp.minimum(s + jax.lax.cummin(t), BIG)
-        row = jnp.where(valid_row, row, BIG)
-        return i + 1, row
-
-    i, last = jax.lax.while_loop(cond, step, (jnp.int32(0), prev0))
-    return jnp.where(i == n, last[w], jnp.min(last))
+    return band_dp_early(*_band_costs_mv(x, y, w, p, d), w, bound)
 
 
 def dtw_batch_mv(
