@@ -45,8 +45,15 @@ def query_mix(
     """``repeat_frac`` exact repeats drawn from a pool of ``repeat_pool``
     near-duplicates (answer-cache and coalescing targets),
     ``near_frac`` near-duplicates of database rows at ``near_sigma``,
-    the rest cold random walks, in one shuffled order.  With both
-    fractions 0 every query is a fresh walk."""
+    the rest cold random walks.  With both fractions 0 every query is a
+    fresh walk.
+
+    The order is shuffled but stratified: the j-th of a kind's m queries
+    lies at a random point of the j-th m-th of the order, so every
+    prefix holds each kind in its share to within two queries.  A window
+    answers a prefix, and with a plain shuffle the seed set how many of
+    its queries were repeats the answer cache takes (141 to 169 of the
+    first 520 at five seeds), and with them the window's work."""
     n_rows, length = rows.shape
     n_rep = int(count * repeat_frac)
     n_near = int(count * near_frac)
@@ -67,7 +74,10 @@ def query_mix(
     if n_cold:
         parts.append(random_walks(rng, n_cold, length))
     work = np.concatenate(parts, axis=0).astype(np.float32)
-    return work[rng.permutation(len(work))]
+    slots = np.concatenate(
+        [(np.arange(len(p)) + rng.random(len(p))) / len(p) for p in parts]
+    )
+    return work[np.argsort(slots, kind="stable")]
 
 
 def planted_walk_stream(

@@ -76,6 +76,39 @@ class CompileCounter:
             self.count += 1
 
 
+class GcClock:
+    """Counts the garbage collector's passes, and the seconds they take,
+    while it is on: a full pass over a JAX process's heap stands the
+    host still, and in a host-bound window that shows in the rate."""
+
+    def __init__(self):
+        import gc
+
+        self.on, self._t = False, None
+        self.passes, self.seconds = [0, 0, 0], [0.0, 0.0, 0.0]
+        gc.callbacks.append(self._seen)
+
+    def _seen(self, phase, info):
+        if phase == "start":
+            self._t = time.perf_counter()
+        elif self._t is not None:
+            if self.on:
+                gen = info["generation"]
+                self.passes[gen] += 1
+                self.seconds[gen] += time.perf_counter() - self._t
+            self._t = None
+
+
+def host_usage() -> tuple[float, int]:
+    """This process's CPU seconds and involuntary context switches:
+    told apart over the window, they show whether a slow run lost the
+    host's cores or did more work."""
+    import resource
+
+    ru = resource.getrusage(resource.RUSAGE_SELF)
+    return ru.ru_utime + ru.ru_stime, ru.ru_nivcsw
+
+
 class MetricContext:
     """What a per-layer metric reader may read."""
 
@@ -102,6 +135,7 @@ def run(args, spec, cell, devices, device, *, root=ROOT, log=sys.stderr) -> dict
     service = harness.service_module(config["service"], root)
     spans = harness.Spans(traced=bool(args.trace))
     compiles = CompileCounter()
+    gc_clock = GcClock()
 
     sess = service.Cell(config, traffic, args.seed, args.seconds, spans)
     sess.warmup()
@@ -118,19 +152,24 @@ def run(args, spec, cell, devices, device, *, root=ROOT, log=sys.stderr) -> dict
         options = jax.profiler.ProfileOptions()
         options.python_tracer_level = 0
         jax.profiler.start_trace(str(trace_dir), profiler_options=options)
-    compiles.on = True
+    compiles.on = gc_clock.on = True
+    usage0 = host_usage()
     try:
         window = sess.run_window(args.seconds)
     finally:
-        compiles.on = False
+        compiles.on = gc_clock.on = False
         if args.trace:
             jax.profiler.stop_trace()
+    usage = [b - a for a, b in zip(usage0, host_usage())]
     counts = {
         k: v for k, v in sess.counters.items() if isinstance(v, (int, float))
     }
     print(
         f"window: {sess.counters['window_s']:.3f} s, compiles inside it: "
-        f"{compiles.count}, counters: {counts}",
+        f"{compiles.count}, gc passes by generation: {gc_clock.passes} "
+        f"taking {[round(x, 4) for x in gc_clock.seconds]} s, cpu {usage[0]:.2f} s, "
+        f"involuntary switches {usage[1]}, "
+        f"counters: {counts}",
         file=log,
         flush=True,
     )

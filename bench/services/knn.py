@@ -175,6 +175,12 @@ class Cell:
             "n_candidates": sum(int(a.stats.n_candidates) for a in executed),
             "batches": [b for b in self.batches if b[0] >= t0],
         }
+        batch_ms = [1e3 * (b[1] - b[0]) for b in self.counters["batches"]]
+        if batch_ms:
+            self.counters.update(
+                batch_ms_median=float(np.median(batch_ms)),
+                batch_ms_max=max(batch_ms),
+            )
         return {
             "attempted": len(records),
             "failed": len(records) - len(answered),

@@ -1,13 +1,14 @@
 """Subsequence matching over a stream, served by ``QueryEngine.open_stream``.
 
-Set-up makes the template bank and the whole stream from the seed,
-calibrates each template's threshold on the stream's head, builds the
-session, and feeds a separate warm-up stream through a stream session of
-its own so that every program the window drives is compiled.  The window
-feeds the stream in fixed chunks through ``StreamSession.feed`` as fast
-as the session takes them.  Afterwards the session is closed, which
-finalises the matches of the samples fed, and every match is compared
-with the plain reference over the same samples.
+Set-up makes the template bank and the whole stream from the seed, sets
+each template's threshold at a fixed alarm share of windows spread over
+the whole stream, builds the session, and feeds a separate warm-up
+stream through a stream session of its own so that every program the
+window drives is compiled.  The window feeds the stream in fixed chunks
+through ``StreamSession.feed`` as fast as the session takes them.
+Afterwards the session is closed, which finalises the matches of the
+samples fed, and every match is compared with the plain reference over
+the same samples.
 """
 
 from __future__ import annotations
@@ -22,20 +23,29 @@ from bench.reference.dtw import cross_distances
 
 
 def calibrate_thresholds(
-    templates: np.ndarray, head: np.ndarray, w: int, p: int, *, windows: int,
-    stride: int, frac: float,
+    templates: np.ndarray, stream: np.ndarray, w: int, p: int, *, windows: int,
+    alarm_share: float,
 ) -> np.ndarray:
-    """Per-template threshold: ``frac`` times the median DTW distance of
-    ``windows`` z-normalised head windows, ``stride`` samples apart
-    (copied from ``repro.launch.stream.calibrate_thresholds``, which
-    takes its windows back to back)."""
+    """Per-template threshold: the ``alarm_share`` quantile of the
+    template's DTW distances to ``windows`` z-normalised windows whose
+    starts are spread evenly over the whole of ``stream``.
+
+    Each template then alarms on about ``alarm_share`` of the stream's
+    windows whatever the seed, as a monitoring user sets a threshold by
+    the alarm rate they will tolerate, so the seed does not set how many
+    windows reach the DP and the exclusion.  (``repro.launch.stream
+    .calibrate_thresholds`` takes a fraction of the median distance of
+    the stream's first windows instead.)"""
     n = templates.shape[1]
-    starts = np.arange(windows) * stride
-    if starts[-1] + n > head.size:
-        raise ValueError("stream head too short to calibrate thresholds")
-    wins = np.stack([head[s : s + n] for s in starts])
+    if stream.size < n + windows - 1:
+        raise ValueError(
+            f"a stream of {stream.size} samples holds fewer than {windows} "
+            f"distinct windows of {n}"
+        )
+    starts = np.linspace(0, stream.size - n, windows).round().astype(np.int64)
+    wins = np.lib.stride_tricks.sliding_window_view(stream, n)[starts]
     dist = cross_distances(ref.znorm_rows(templates), ref.znorm_rows(wins), w, p)
-    return frac * np.median(dist, axis=1)
+    return np.quantile(dist, alarm_share, axis=1)
 
 
 class Cell:
@@ -67,8 +77,7 @@ class Cell:
             config["w"],
             config["p"],
             windows=traffic["calibration_windows"],
-            stride=traffic["calibration_stride"],
-            frac=traffic["threshold_frac"],
+            alarm_share=traffic["alarm_share"],
         )
         self.db = Database.build(
             self.templates,
@@ -124,6 +133,7 @@ class Cell:
         self.session = sess
         out = []
         fed = 0
+        chunk_s = []
         t0 = time.perf_counter()
         t_stop = t0 + seconds
         with self.spans.span("bench.window"):
@@ -133,8 +143,9 @@ class Cell:
                         f"the window fed all {stream.size} pre-generated "
                         f"samples; raise max_samples_per_s in the traffic file"
                     )
-                with self.spans.span("stream.feed"):
+                with self.spans.span("stream.feed") as s:
                     out.extend(sess.feed(stream[fed : fed + chunk]))
+                chunk_s.append(time.perf_counter() - s.t0)
                 fed += chunk
         t_end = time.perf_counter()
         st = sess.stats
@@ -147,6 +158,8 @@ class Cell:
             "windows": windows,
             "full_dtw": int(st.full_dtw.sum()),
             "chunks": fed // chunk,
+            "chunk_ms_median": 1e3 * float(np.median(chunk_s)),
+            "chunk_ms_max": 1e3 * max(chunk_s),
         }
         # finalise what was fed (not timed): the flush evaluates the last
         # partial block and settles every pending exclusion decision

@@ -42,7 +42,7 @@ TINY_TRAFFIC = {
     "closed32-cold": {"clients": 8, "max_qps": 20000},
     "closed32-mixed": {"clients": 8, "max_qps": 20000},
     "stream-chunk1024": {"chunk": 256, "plant_every": 400,
-                         "calibration_windows": 16, "calibration_stride": 16,
+                         "calibration_windows": 256,
                          "warmup_chunks": 2, "max_samples_per_s": 400000},
 }
 

@@ -141,6 +141,24 @@ def test_generators_are_seeded():
     assert len(np.unique(mix, axis=0)) <= 100 - 30 + 8
 
 
+@pytest.mark.parametrize("seed", [0, 7, 2**31 + 5])
+def test_query_mix_keeps_each_kind_in_its_share_in_every_prefix(seed):
+    rows = _walks(13, 50, 8) + 1000.0  # cold walks start at 0, these do not
+    count, shares = 1000, (0.3, 0.4, 0.3)
+    mix = generate.query_mix(generate.rng_for(seed, generate.TRAFFIC), rows,
+                             count, repeat_frac=shares[0], near_frac=shares[1],
+                             repeat_pool=2, near_sigma=0.25)
+    uniq, inverse, counts = np.unique(mix, axis=0, return_inverse=True,
+                                      return_counts=True)
+    repeat = counts[inverse.ravel()] > 1
+    cold = mix[:, 0] == 0
+    kinds = np.where(repeat, 0, np.where(cold, 2, 1))
+    seen = np.cumsum(np.eye(3)[kinds], axis=0)
+    due = np.arange(1, count + 1)[:, None] * np.array(shares)
+    assert np.abs(seen - due).max() <= 2
+    assert seen[-1].tolist() == [300, 400, 300]
+
+
 def test_planted_stream_is_continuous_and_holds_its_plants():
     rng = np.random.default_rng(12)
     templates = generate.random_walks(rng, 2, 32)
